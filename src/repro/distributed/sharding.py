@@ -20,40 +20,15 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
-
-
-# -- jax version compatibility ------------------------------------------------
-# The mesh construction API moved between jax releases: AbstractMesh switched
-# from a tuple of (name, size) pairs to positional (sizes, names), AxisType
-# only exists on newer jax, and make_mesh only grew axis_types later. These
-# helpers are the single place the repo adapts; call sites (launch/mesh.py,
-# tests, subprocess scripts) stay version-agnostic.
-
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]) -> AbstractMesh:
-    """AbstractMesh(sizes, names) across jax versions."""
-    try:                                   # newer jax: positional (sizes, names)
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:                      # jax <= 0.4.x: ((name, size), ...)
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-def auto_axis_types(n: int):
-    """(AxisType.Auto,) * n where supported, else None (older jax default)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return None if axis_type is None else (axis_type.Auto,) * n
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]) -> Mesh:
-    """jax.make_mesh with Auto axis types where the installed jax supports it."""
-    types = auto_axis_types(len(tuple(axis_names)))
-    if types is not None:
-        try:
-            return jax.make_mesh(tuple(axis_sizes), tuple(axis_names),
-                                 axis_types=types)
-        except TypeError:
-            pass
-    return jax.make_mesh(tuple(axis_sizes), tuple(axis_names))
+    """jax.make_mesh with Auto axis types: shardings stay compiler-propagated
+    hints, which the PartitionSpec rules below are written for."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_sizes), names,
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -142,7 +142,11 @@ def sparse_ffn_segments_fused_kernel(
     B, D = x.shape
     S = seg_ids.shape[0]
     wspec = pl.BlockSpec((seg_size, D), lambda s, ids: (ids[s], 0))
-    sspec = pl.BlockSpec((1, seg_size), lambda s, ids: (s, 0))
+    # A (1, seg) block of an [S, seg] array breaks Mosaic's rule that a
+    # block's last two dims be (8k, 128k) or the full array dims; a unit
+    # middle axis makes them (1, seg), the full dims, for any seg.
+    scale_tiles = scale_tiles.reshape(S, 1, seg_size)
+    sspec = pl.BlockSpec((None, 1, seg_size), lambda s, ids: (s, 0, 0))
     in_specs = [
         pl.BlockSpec((B, D), lambda s, ids: (0, 0)),   # x resident in VMEM
         sspec,                                         # per-neuron multiplier

@@ -174,12 +174,14 @@ def paged_decode_kernel(
                      lambda b, h, p, pt, cur: (pt[b, p], h, 0, 0)),
     ]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1, page_size),
-                         lambda b, h, p, pt, cur: (pt[b, p], h, 0)),
-            pl.BlockSpec((1, 1, page_size),
-                         lambda b, h, p, pt, cur: (pt[b, p], h, 0)),
-        ]
+        # A unit axis before page_size makes the scale block's last two dims
+        # (1, page_size), the full array dims, as Mosaic requires; a
+        # (1, 1, page_size) block of [pages+1, KV, page_size] is refused.
+        k_scale = k_scale.reshape(k_scale.shape[:2] + (1, page_size))
+        v_scale = v_scale.reshape(v_scale.shape[:2] + (1, page_size))
+        sspec = pl.BlockSpec((None, None, 1, page_size),
+                             lambda b, h, p, pt, cur: (pt[b, p], h, 0, 0))
+        in_specs += [sspec, sspec]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
@@ -195,7 +197,7 @@ def paged_decode_kernel(
         def kern(pt_ref, cur_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                  m_ref, l_ref, acc_ref):
             b, page = pl.program_id(0), pl.program_id(2)
-            scales = (ks_ref[0, 0][:, None], vs_ref[0, 0][:, None])
+            scales = (ks_ref[...].T, vs_ref[...].T)          # [page_size, 1]
             _paged_core(q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], scales,
                         cur_ref[b], page, page_size, pages, scale,
                         m_ref, l_ref, acc_ref, o_ref)

@@ -17,18 +17,22 @@ import time
 
 import jax
 
-from repro.configs import ASSIGNED_CONFIGS, get_config
+from repro.configs import ALL_CONFIGS, get_config
 from repro.models import build_model
 from repro.store.packer import build_pack
-from repro.utils import add_verbosity_flag, configure_logging, get_logger
+from repro.utils import (add_verbosity_flag, configure_logging,
+                         enable_compile_cache, get_logger)
 
 logger = get_logger("launch.pack")
 
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen2-7b", choices=sorted(ASSIGNED_CONFIGS))
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--arch", default="qwen2-7b", choices=sorted(ALL_CONFIGS))
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="tiny same-family config (default); --no-reduced "
+                         "packs the published widths and depth")
     ap.add_argument("--out", required=True, help="output NeuronPack path")
     ap.add_argument("--calib-tokens", type=int, default=512,
                     help="random calibration tokens to trace (streamed to "
@@ -52,14 +56,16 @@ def main(argv=None) -> None:
     ap.add_argument("--d-model", type=int, default=None)
     ap.add_argument("--d-ff", type=int, default=None)
     ap.add_argument("--n-layers", type=int, default=None)
-    ap.add_argument("--vocab", type=int, default=512)
+    ap.add_argument("--vocab", dest="vocab_size", type=int, default=None,
+                    help="override the vocabulary size (default: the "
+                         "config's own, capped at 512 by --reduced)")
     ap.add_argument("--seed", type=int, default=0)
     add_verbosity_flag(ap)
     args = ap.parse_args(argv)
     configure_logging(args.verbose)
 
-    overrides = dict(vocab_size=args.vocab, activation="relu")
-    for key in ("d_model", "d_ff", "n_layers"):
+    overrides = dict(activation="relu")
+    for key in ("d_model", "d_ff", "n_layers", "vocab_size"):
         val = getattr(args, key)
         if val is not None:
             overrides[key] = val
@@ -91,4 +97,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

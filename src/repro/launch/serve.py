@@ -16,28 +16,42 @@ as slots free up; `--stream` prints tokens as they are emitted. The overload
 knobs `--queue-limit / --ttft-slo / --itl-slo` arm bounded-queue backpressure
 and deadline retirement (finish_reason "rejected" / "timeout") — see the
 README "Load testing & SLOs" section.
+
+`--reduced` (the default) shrinks the config to a tiny same-family variant;
+`--no-reduced` serves the published widths and depth, e.g.
+
+  PYTHONPATH=src python -m repro.launch.serve --arch opt-1.3b --no-reduced \
+      --mode offload --prefetch --slots 4 --page-size 16 --num-pages 64
+
+`serve(argv)` runs the whole driver and returns what it measured; `main`
+exits non-zero when any request finished with finish_reason "error".
 """
 import argparse
 import time
+from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 
-from repro.configs import ASSIGNED_CONFIGS, get_config
+from repro.configs import ALL_CONFIGS, get_config
 from repro.core import EngineConfig, IOScheduler
 from repro.models import build_model
 from repro.obs import enable_tracing
 from repro.serving.engine import Request, build_offload_runtime
 from repro.serving.server import InferenceServer
-from repro.utils import add_verbosity_flag, configure_logging, get_logger
+from repro.utils import (add_verbosity_flag, configure_logging,
+                         enable_compile_cache, get_logger)
 
 logger = get_logger("launch.serve")
 
 
-def main() -> None:
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen2-7b", choices=sorted(ASSIGNED_CONFIGS))
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--arch", default="qwen2-7b", choices=sorted(ALL_CONFIGS))
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="tiny same-family config (default); --no-reduced "
+                         "serves the published widths and depth")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
@@ -109,7 +123,9 @@ def main() -> None:
                          "(more concurrency; page pressure may preempt the "
                          "lowest-priority request, finish_reason='preempted') "
                          "instead of the strict worst-case reservation")
-    ap.add_argument("--vocab", type=int, default=512)
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="override the vocabulary size (default: the "
+                         "config's own, capped at 512 by --reduced)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="record a Chrome trace-event / Perfetto timeline of "
@@ -117,7 +133,19 @@ def main() -> None:
                          "worker, per-request lanes) and write it to PATH; "
                          "open it at https://ui.perfetto.dev")
     add_verbosity_flag(ap)
-    args = ap.parse_args()
+    return ap
+
+
+def serve(argv: Optional[List[str]] = None) -> Dict[str, Any]:
+    """Run the serving driver on `argv` and return what it measured:
+    `results` (one `Result` per request, in submission order), the server's
+    `stats`, `io_summary` (offload mode, else None), `page_summary` (paged
+    KV, else None) and `timings` in seconds (`init`: weights; `calibration`:
+    calibration forward, placement search and lookahead training; `first_step`:
+    the first server step, which compiles prefill and decode; `serve`: the
+    whole serving loop, first step included); plus the `model`, `params` and
+    closed `offload` runtime (None in resident mode) it served with."""
+    args = _parser().parse_args(argv)
     configure_logging(args.verbose)
     tracer = enable_tracing() if args.trace_out else None
     if bool(args.page_size) != bool(args.num_pages):
@@ -132,12 +160,18 @@ def main() -> None:
                              "pack (build an identity pack with "
                              "repro.launch.pack --no-placement)")
 
-    overrides = dict(vocab_size=args.vocab, kv_quant=args.kv_quant)
+    overrides = dict(kv_quant=args.kv_quant)
+    if args.vocab is not None:
+        overrides["vocab_size"] = args.vocab
     if mode == "offload":
         overrides["activation"] = "relu"   # ReLU sparsity (paper's setting)
     cfg = get_config(args.arch, reduced=args.reduced, **overrides)
+    timings = {}
+    t0 = time.perf_counter()
     model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(args.seed))
+    params = jax.block_until_ready(
+        model.init_params(jax.random.PRNGKey(args.seed)))
+    timings["init"] = time.perf_counter() - t0
     rng = np.random.default_rng(args.seed)
 
     offload = None
@@ -164,6 +198,7 @@ def main() -> None:
                 train_lookahead=args.prefetch)
             logger.info("offload runtime calibrated: %d layer engines in %.2fs",
                         offload.n_layers, time.perf_counter() - t0)
+        timings["calibration"] = time.perf_counter() - t0
         scheduler = IOScheduler(overlap=not args.no_overlap)
 
     reqs = [Request(uid=i,
@@ -206,7 +241,9 @@ def main() -> None:
                 handles.append(server.submit(reqs[i], on_token=on_token))
                 i += 1
             if server.has_work:
+                t_step = time.perf_counter()
                 server.step()
+                timings.setdefault("first_step", time.perf_counter() - t_step)
             elif i < len(reqs):                 # idle until the next arrival
                 time.sleep(min(arrivals[i] - now, 0.01))
     except KeyboardInterrupt:
@@ -220,6 +257,7 @@ def main() -> None:
     finally:
         server.close()
     wall = time.perf_counter() - t0
+    timings["serve"] = wall
     results = [h.result for h in handles]
     n_tok = sum(len(r.tokens) for r in results)
     n_err = sum(r.finish_reason == "error" for r in results)
@@ -257,8 +295,9 @@ def main() -> None:
                     pg["registry_entries"], pg["prefix_evictions"],
                     pg["page_deferrals"], pg["preemptions"])
 
+    io = None
     if mode == "offload":
-        s = offload.io_summary()
+        io = s = offload.io_summary()
         logger.info("offload I/O: %.2fms/token run_len=%.2f bw=%.0fMB/s hit=%.2f",
                     s["io_seconds_per_token"] * 1e3, s["mean_run_length"],
                     s["effective_bandwidth"] / 1e6, s["cache_hit_rate"])
@@ -299,7 +338,19 @@ def main() -> None:
         logger.info("trace: %d events (%d dropped) -> %s; open it at "
                     "https://ui.perfetto.dev", len(events), tracer.dropped,
                     args.trace_out)
+    return dict(results=results, stats=server.stats, io_summary=io,
+                page_summary=pg, timings=timings,
+                model=model, params=params, offload=offload)
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    out = serve(argv)
+    n_err = sum(r.finish_reason == "error" for r in out["results"])
+    if n_err:
+        raise SystemExit(f"{n_err} request(s) finished with "
+                         f"finish_reason='error'")
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -144,7 +144,10 @@ class Tracer:
     def _ring(self) -> _Ring:
         ring = getattr(self._local, "ring", None)
         if ring is None:
-            tid = threading.get_ident()
+            # The OS thread id, not get_ident(): CPython reuses an ident as
+            # soon as its thread exits, and a reused ident would replace the
+            # finished thread's ring in `_rings`, losing its events.
+            tid = threading.get_native_id()
             ring = _Ring(self.capacity_per_thread)
             with self._lock:
                 self._rings[tid] = (ring, threading.current_thread().name)

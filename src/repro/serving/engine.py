@@ -758,6 +758,15 @@ class OffloadedFFNRuntime:
         buffers (segment ids + scale tiles) via jnp.asarray — no fresh
         concatenate/pad in the decode loop."""
         from repro.kernels import ops
+        return ops.sparse_ffn_segments_fused(
+            h, *self.segment_kernel_inputs(layer, ids),
+            seg_size=self.engine_cfg.kernel_seg_size,
+            activation=self.cfg.activation)
+
+    def segment_kernel_inputs(self, layer: int, ids: np.ndarray) -> tuple:
+        """`(w_up, w_down, seg_ids, scale_tiles, w_gate)`: the weight and
+        segment arguments `ops.sparse_ffn_segments_fused` takes to serve the
+        activated neuron `ids` (logical) at dense `layer`."""
         eng = self.engines[layer]
         seg = self.engine_cfg.kernel_seg_size
         w_up, w_down, w_gate, base = self._segment_weight_mats(layer)
@@ -773,10 +782,8 @@ class OffloadedFFNRuntime:
         tiles[:padded] = 0.0
         rows = np.searchsorted(seg_u, seg_of)
         tiles[rows, phys % seg] = base[phys]
-        return ops.sparse_ffn_segments_fused(
-            h, w_up, w_down, jnp.asarray(id_buf[:padded]),
-            jnp.asarray(tiles[:padded]), w_gate,
-            seg_size=seg, activation=self.cfg.activation)
+        return (w_up, w_down, jnp.asarray(id_buf[:padded]),
+                jnp.asarray(tiles[:padded]), w_gate)
 
     def _seg_ids_buf(self, padded: int) -> np.ndarray:
         buf = self._staging.get(("seg_ids",))

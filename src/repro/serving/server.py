@@ -93,6 +93,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs.base import ModelConfig
 from repro.core.pipeline import IOScheduler
 from repro.core.predictor import PredictorParams, predict_mask
 from repro.obs import get_metrics, get_tracer
@@ -386,8 +387,9 @@ class InferenceServer:
                 None if self._pool is not None else transformer.unstack_groups(
                     model.init_cache(max_slots, max_len, swa=swa), cfg))
             self._param_groups = transformer.unstack_groups(
-                params["stack"], cfg)
-            self._w_ups = _oracle_w_ups(model, params) if oracle else None
+                _offload_decode_stack(params["stack"], cfg, oracle), cfg)
+            self._w_ups = (_oracle_w_ups(cfg, self._param_groups) if oracle
+                           else None)
             if self._w_ups is not None and len(self._w_ups) != offload.n_layers:
                 raise ValueError(
                     f"runtime has {offload.n_layers} layer engines, model has "
@@ -1211,17 +1213,30 @@ class InferenceServer:
         return rows, token_wall, req_io, over
 
 
-def _oracle_w_ups(model: Model, params: Any) -> List[jnp.ndarray]:
-    """Resident w_up handles per dense layer, in capture order — the exact
-    ReLU support oracle the predictor approximates. The simulated flash still
-    pays for every neuron the mask selects."""
-    cfg = model.cfg
-    P = transformer.stack_period(cfg)
-    G = cfg.n_layers // P
+def _offload_decode_stack(stack: Any, cfg: ModelConfig, oracle: bool) -> Any:
+    """The part of the stacked params the offload decode reads. The runtime
+    serves every dense FFN, so of each dense FFN only `w_up` stays, and only
+    for the oracle's masks: the per-group copy then holds no weight it never
+    reads (1.6 GB of HBM at OPT-1.3B)."""
     ffns = cfg.ffn_kinds()
-    w_ups = []
-    for g in range(G):
-        for j in range(P):
-            if ffns[j] == "dense":
-                w_ups.append(params["stack"][f"sub_{j}"]["ffn"]["w_up"][g])
-    return w_ups
+    stack = dict(stack)
+    for j in range(transformer.stack_period(cfg)):
+        if ffns[j] == "dense":
+            sub = stack[f"sub_{j}"]
+            stack[f"sub_{j}"] = {
+                **sub, "ffn": {"w_up": sub["ffn"]["w_up"]} if oracle else {}}
+    return stack
+
+
+def _oracle_w_ups(cfg: ModelConfig,
+                  param_groups: List[Any]) -> List[jnp.ndarray]:
+    """Resident w_up handles per dense layer, in capture order — the exact
+    ReLU support oracle the predictor approximates. The handles are the
+    per-group arrays the server already holds, so the oracle costs no second
+    copy of the weights. The simulated flash still pays for every neuron the
+    mask selects."""
+    ffns = cfg.ffn_kinds()
+    return [group[f"sub_{j}"]["ffn"]["w_up"]
+            for group in param_groups
+            for j in range(transformer.stack_period(cfg))
+            if ffns[j] == "dense"]

@@ -7,6 +7,7 @@ import json
 import logging
 import os
 import time
+from pathlib import Path
 from typing import Any, Dict, Iterator
 
 import jax
@@ -50,6 +51,26 @@ def configure_logging(verbose: int = 0) -> None:
     """Apply a parsed `--verbose` count to the shared logger."""
     if verbose > 0:
         set_log_level(logging.DEBUG)
+
+
+def compile_cache_dir() -> str:
+    """Where JAX's persistent compilation cache lives: $JAX_COMPILATION_CACHE_DIR
+    when set, else `<repo>/.jax_cache`. The default is derived from this
+    file's location, so every run from one checkout finds the same cache."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    return str(Path(__file__).resolve().parents[2] / ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache for an entry point (never
+    for tests) and return its directory. JAX reads
+    $JAX_COMPILATION_CACHE_DIR itself; only the default is set here."""
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @contextlib.contextmanager

@@ -1,21 +1,11 @@
 """Shared fixtures. jax is initialised here with the default (1) device count —
 the 512-device dry-run flag is set only inside subprocesses (see test_dryrun.py),
 never globally."""
-import sys
-
 import jax
 import numpy as np
 import pytest
 
 jax.devices()  # lock the backend to 1 CPU device before anything else
-
-try:
-    import hypothesis  # noqa: F401  (real package preferred when installed)
-except ImportError:   # offline container: vendored deterministic fallback
-    sys.path.insert(0, str(__import__("pathlib").Path(__file__).parent))
-    from _hypothesis_fallback import install_as_hypothesis
-
-    install_as_hypothesis(sys.modules)
 
 
 @pytest.fixture(scope="session")

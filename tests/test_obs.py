@@ -89,6 +89,18 @@ def test_spans_from_threads_get_distinct_tids(tracer):
     assert {e["tid"] for e in jobs} <= meta_tids
 
 
+def test_spans_from_exited_threads_are_kept(tracer):
+    """A thread that starts after another exited may get its ident; the
+    finished thread's events must survive."""
+    for i in range(4):
+        t = threading.Thread(target=lambda: tracer.instant("job"))
+        t.start()
+        t.join()
+    jobs = [e for e in tracer.events() if e["name"] == "job"]
+    assert len(jobs) == 4
+    assert len({e["tid"] for e in jobs}) == 4
+
+
 def test_ring_buffer_wraparound_keeps_newest():
     tr = Tracer(capacity_per_thread=8)
     for i in range(20):

@@ -6,18 +6,17 @@ import sys
 import jax
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ASSIGNED_CONFIGS, get_config
-from repro.distributed.sharding import (abstract_mesh, batch_spec, cache_specs,
-                                        param_specs)
+from repro.distributed.sharding import batch_spec, cache_specs, param_specs
 from repro.models import build_model
 
 
 def _abstract_mesh(multi_pod=False):
     if multi_pod:
-        return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", sorted(ASSIGNED_CONFIGS))
