@@ -46,7 +46,7 @@ import numpy as np
 from repro.core.cache import make_linking_aligned_cache
 from repro.core.placement import PlacementResult
 from repro.core.storage import IOStats, ManagedReader, NeuronStore, UFSDevice
-from repro.obs import get_tracer
+from repro.obs import get_metrics, get_tracer
 
 
 @dataclasses.dataclass
@@ -222,6 +222,9 @@ class OffloadEngine:
             impl=self.cfg.cache_impl,
         )
         self.history: List[TokenStats] = []
+        # size of each step's true activated union, summed: what the served
+        # set has to cover (read per window against `offload.served_neurons`)
+        self._true_union = get_metrics().counter("offload.true_union_neurons")
 
     @classmethod
     def from_store(cls, store: NeuronStore,
@@ -393,9 +396,11 @@ class OffloadEngine:
             masks = pending.masks
             extra = topup_miss = np.zeros(0, dtype=np.int64)
             n_extra_hits = 0
+            self._true_union.inc(int(pending.union.size))
         else:
             masks = np.atleast_2d(np.asarray(true_masks, dtype=bool))
             true_union = np.flatnonzero(masks.any(axis=0))
+            self._true_union.inc(int(true_union.size))
             extra = np.setdiff1d(true_union, pending.union, assume_unique=True)
             topup_miss = np.zeros(0, dtype=np.int64)
             n_extra_hits = 0

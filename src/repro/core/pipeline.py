@@ -181,21 +181,15 @@ class IOScheduler:
         self.history.append(timing)
         self._stages = []
         self._measured = []
-        tracer = get_tracer()
-        if tracer.enabled:
-            # counter tracks in the exported trace, so the Perfetto timeline
-            # and BENCH_prefetch.json agree by construction (ISSUE 10)
-            tracer.counter("io_model_ms",
-                           serial=timing.serial_seconds * 1e3,
-                           overlapped=timing.overlapped_seconds * 1e3,
-                           io=timing.io_seconds * 1e3)
-            if wall_seconds is not None:
-                tracer.counter(
-                    "io_measured_ms",
-                    wall=timing.measured_wall_seconds * 1e3,
-                    io_busy=timing.measured_io_busy_seconds * 1e3,
-                    exposed=timing.measured_exposed_seconds * 1e3,
-                    hidden=timing.measured_hidden_seconds * 1e3)
+        if wall_seconds is not None:
+            # a counter track of measured host seconds in the exported trace
+            # (the modelled phone-flash seconds stay out of it)
+            get_tracer().counter(
+                "io_measured_ms",
+                wall=timing.measured_wall_seconds * 1e3,
+                io_busy=timing.measured_io_busy_seconds * 1e3,
+                exposed=timing.measured_exposed_seconds * 1e3,
+                hidden=timing.measured_hidden_seconds * 1e3)
         return timing
 
     def predicted_compute_seconds_per_token(self, window: int = 8) -> float:
@@ -211,18 +205,15 @@ class IOScheduler:
         return sum(t.serial_seconds - t.io_seconds for t in hist) / len(hist)
 
     def register_metrics(self, registry=None, prefix: str = "scheduler"):
-        """Register this scheduler's summary fields as live gauges — the
-        measured-mode columns (`wall/busy/exposed/hidden`,
-        `overlap_efficiency`) plus the analytic model, all read lazily from
-        `summary()` so the registry and the legacy reporting surface cannot
-        disagree. Returns the registry used."""
+        """Register this scheduler's measured summary fields as live gauges
+        (`tokens` and the measured `wall/busy/exposed/hidden` columns), read
+        lazily from `summary()` so the registry and the legacy reporting
+        surface cannot disagree. The analytic phone-flash columns stay in
+        `summary()` only: they are a model, not a measurement. Returns the
+        registry used."""
         reg = registry if registry is not None else get_metrics()
         keys = (
             "tokens",
-            "overlap_efficiency",
-            "serial_seconds_per_token",
-            "overlapped_seconds_per_token",
-            "hidden_seconds_per_token",
             "measured_wall_seconds_per_token",
             "measured_serial_seconds_per_token",
             "measured_io_busy_seconds_per_token",

@@ -17,6 +17,7 @@ from repro.configs.base import ModelConfig
 from repro.models import moe as moe_lib
 from repro.models import ssm
 from repro.kernels import ops
+from repro.obs import get_tracer
 from repro.models.kvcache import (KVCache, PagedKVCache, PagedQuantKVCache,
                                   QuantKVCache, SWACache, attend_full_cache,
                                   attend_swa_cache,
@@ -467,11 +468,14 @@ def stack_decode_step_layerwise(
     `ffn_pre_act`, so calibration traces and serving agree on layer ids.
     `page_tables` routes attention sublayers through a paged arena exactly as
     in `stack_decode_step` — the one page table serves every layer group.
+    Each sublayer's mixer (with its residual) runs under a `repro.obs`
+    `attention` span and its FFN under an `ffn` span.
     """
     P = stack_period(cfg)
     kinds, ffns = cfg.layer_kinds(), cfg.ffn_kinds()
     B = x.shape[0]
     pos_arr = _decode_positions(position, B)
+    tr = get_tracer()
     h = x
     dense_idx = 0
     new_groups: List[Params] = []
@@ -481,22 +485,25 @@ def stack_decode_step_layerwise(
             sp = group_params[f"sub_{j}"]
             cj = group_cache[f"sub_{j}"]
             kind, ffn = kinds[j], ffns[j]
-            mix, cj = _mixer_decode(sp, cj, h, pos_arr, position, cfg, kind,
-                                    window, page_tables=page_tables)
-            h = h + mix
+            with tr.span("attention"):
+                mix, cj = _mixer_decode(sp, cj, h, pos_arr, position, cfg,
+                                        kind, window, page_tables=page_tables)
+                h = h + mix
             if ffn != "none":
-                normed2 = apply_norm(sp["norm2"], h, cfg)
-                if ffn == "dense":
-                    if ffn_override is not None:
-                        y2 = ffn_override(dense_idx, normed2)
-                    elif cfg.serve_sparse:
-                        y2 = sparse_ffn_decode(sp["ffn"], sp["ffn_pred"], normed2, cfg)
+                with tr.span("ffn"):
+                    normed2 = apply_norm(sp["norm2"], h, cfg)
+                    if ffn == "dense":
+                        if ffn_override is not None:
+                            y2 = ffn_override(dense_idx, normed2)
+                        elif cfg.serve_sparse:
+                            y2 = sparse_ffn_decode(sp["ffn"], sp["ffn_pred"],
+                                                   normed2, cfg)
+                        else:
+                            y2, _ = ffn_forward(sp["ffn"], normed2, cfg)
+                        dense_idx += 1
                     else:
-                        y2, _ = ffn_forward(sp["ffn"], normed2, cfg)
-                    dense_idx += 1
-                else:
-                    y2, _ = moe_lib.moe_forward(sp["ffn"], normed2, cfg)
-                h = h + y2
+                        y2, _ = moe_lib.moe_forward(sp["ffn"], normed2, cfg)
+                    h = h + y2
             new_cache[f"sub_{j}"] = cj
         new_groups.append(new_cache)
     return h, new_groups

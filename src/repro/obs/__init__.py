@@ -7,7 +7,11 @@ Two cooperating pieces:
   trace-event JSON that loads in https://ui.perfetto.dev. Disabled by
   default via a no-op singleton, so instrumentation sites cost ~a no-op
   method call when tracing is off (gated <1% of step time by
-  ``benchmarks/obs_overhead.py``; <5% enabled).
+  ``benchmarks/obs_overhead.py``; <5% enabled). Every span of a
+  recording tracer also opens a ``jax.profiler.TraceAnnotation``
+  (``repro.<name>``), so under the JAX profiler the spans share the
+  device's clock; this package is the one place in ``src/`` that calls
+  ``jax.profiler``.
 - :mod:`repro.obs.metrics` — a :class:`MetricsRegistry` of counters,
   gauges, and log-bucketed histograms with ``snapshot()``/``delta()``
   semantics. Existing stat objects register gauge callables into it.

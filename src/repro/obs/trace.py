@@ -18,6 +18,15 @@ Timestamps come from ``time.perf_counter`` (monotonic), rebased to the
 tracer's construction time and expressed in microseconds, which is the unit
 the trace-event format expects.
 
+Profiler clock: every ``span()`` of a recording tracer also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so under
+``jax.profiler.trace`` each span lands in the profiler's ``.xplane.pb`` on
+the same clock as the device's ops, on the thread that ran it. With no
+profiler session active an annotation costs about a microsecond. It
+carries no args (the profiler would fold them into the event's name); the
+ring keeps them as before. ``complete()``, ``instant()`` and ``counter()``
+stay ring-only.
+
 Virtual tracks: ``complete(..., track="req 7")`` and
 ``instant(..., track=...)`` place events on a named synthetic thread lane
 instead of the calling thread's lane.  The server uses this to give every
@@ -31,6 +40,8 @@ import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax.profiler
 
 __all__ = [
     "Tracer",
@@ -88,7 +99,7 @@ class _Span:
     counts after a read returns).
     """
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self._tracer = tracer
@@ -102,12 +113,15 @@ class _Span:
             self.args.update(kw)
 
     def __enter__(self) -> "_Span":
+        self._ann = jax.profiler.TraceAnnotation("repro." + self.name)
+        self._ann.__enter__()
         self._t0 = self._tracer.now()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = self._tracer.now()
         self._tracer._emit(self._t0, t1 - self._t0, "X", self.name, self.args)
+        self._ann.__exit__(*exc)
         return False
 
 

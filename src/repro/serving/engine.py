@@ -59,7 +59,7 @@ from repro.core.sparse_ffn import sparse_ffn_from_bundles
 from repro.core.storage import NeuronStore, UFSDevice
 from repro.models import transformer
 from repro.models.model import Model
-from repro.obs import get_tracer
+from repro.obs import get_metrics, get_tracer
 
 
 @dataclasses.dataclass
@@ -358,6 +358,12 @@ class OffloadedFFNRuntime:
         self._worker_disabled = False
         self._restarts_used = 0
         self._inflight: set = set()
+        # per-window readings of the served set against the true one
+        # (`offload.true_union_neurons`, counted by the layer engines): the
+        # neurons the segment kernel serves and the segment rows it reads
+        reg = get_metrics()
+        self._served_neurons = reg.counter("offload.served_neurons")
+        self._segment_rows = reg.counter("offload.segment_rows")
 
     @classmethod
     def from_pack(
@@ -774,6 +780,8 @@ class OffloadedFFNRuntime:
         seg_of = phys // seg
         seg_u = np.unique(seg_of)
         S = int(seg_u.size)
+        self._served_neurons.inc(int(phys.size))
+        self._segment_rows.inc(S * seg)
         padded = -(-max(S, 1) // self.SEG_ID_BUCKET) * self.SEG_ID_BUCKET
         id_buf = self._seg_ids_buf(padded)
         id_buf[:S] = seg_u

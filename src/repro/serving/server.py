@@ -843,26 +843,26 @@ class InferenceServer:
             prompt = jnp.asarray(np.asarray(r.prompt, dtype=np.int32)[None])
             tr = get_tracer()
             t0u = tr.now()
-            t0 = time.perf_counter()
-            small = self.model.init_cache(1, self.max_len, swa=self.swa)
-            logits, small = self._prefill_fn(self.params, prompt, small)
-            row = np.asarray(logits[0, -1], dtype=np.float32)  # forces the sync
-            handle.prefill_seconds = time.perf_counter() - t0
+            with tr.span("prefill", uid=r.uid, prompt_len=T, slot=slot):
+                t0 = time.perf_counter()
+                small = self.model.init_cache(1, self.max_len, swa=self.swa)
+                logits, small = self._prefill_fn(self.params, prompt, small)
+                row = np.asarray(logits[0, -1], dtype=np.float32)  # the sync
+                handle.prefill_seconds = time.perf_counter() - t0
             t1u = tr.now()
-            tr.complete("prefill", t0u, t1u, uid=r.uid, prompt_len=T,
-                        slot=slot)
             # mirrored onto the request's own lane, so one Perfetto row shows
             # the request's whole life (prefill + every decode span)
             tr.complete("prefill", t0u, t1u, track=f"req {r.uid}", uid=r.uid)
             self.stats.prefill_seconds += handle.prefill_seconds
             self.stats.admitted += 1
-            if self._pool is not None:
-                # the table was registered in _tables before the prefill, so
-                # any failure below releases the pages via the _retire path
-                self._pool.write_prompt(table, small)
-                self._pool.register_prefixes(prompt_np, table)
-            else:
-                self._write_slot(slot, small)
+            with tr.span("page_write"):
+                if self._pool is not None:
+                    # the table was registered in _tables before the prefill,
+                    # so any failure below releases the pages via _retire
+                    self._pool.write_prompt(table, small)
+                    self._pool.register_prefixes(prompt_np, table)
+                else:
+                    self._write_slot(slot, small)
             self._slot_handle[slot] = handle
             self._slot_pos[slot] = T
             handle.state = RequestState.DECODE
@@ -1061,13 +1061,14 @@ class InferenceServer:
         active = self._active_mask()
         tr = get_tracer()
         t0u = tr.now()
-        if self.mode == "resident":
-            logits_rows, token_wall, req_io, over = self._decode_resident()
-        else:
-            logits_rows, token_wall, req_io, over = self._decode_offload(active)
+        with tr.span("decode_step", batch=int(active.sum()),
+                     step=self.stats.decode_steps):
+            if self.mode == "resident":
+                logits_rows, token_wall, req_io, over = self._decode_resident()
+            else:
+                logits_rows, token_wall, req_io, over = \
+                    self._decode_offload(active)
         t1u = tr.now()
-        tr.complete("decode_step", t0u, t1u, batch=int(active.sum()),
-                    step=self.stats.decode_steps)
         self._step_hist.observe(token_wall)
         self.stats.decode_seconds += token_wall
         self.stats.decode_steps += 1
@@ -1112,7 +1113,8 @@ class InferenceServer:
             logits, self._cache = self._decode_fn(
                 self.params, jnp.asarray(self._cur)[:, None],
                 jnp.asarray(self._slot_pos), self._cache)
-        rows = np.asarray(logits[:, 0], dtype=np.float32)   # the per-token sync
+        with get_tracer().span("sync"):
+            rows = np.asarray(logits[:, 0], dtype=np.float32)
         wall = time.perf_counter() - t0
         return rows, wall, np.zeros(self.max_slots), 0.0
 
@@ -1123,11 +1125,13 @@ class InferenceServer:
         oracle (or trained predictor), with retired/free rows zeroed so they
         leave the union — a finished request incurs no further I/O."""
         if self._w_ups is not None:
-            masks = np.asarray(h2 @ self._w_ups[dense_idx] > 0)
+            masks = h2 @ self._w_ups[dense_idx] > 0
         else:
             assert self.offload.predictors is not None, \
                 "oracle=False needs runtime predictors"
-            masks = np.asarray(predict_mask(self.offload.predictors[dense_idx], h2))
+            masks = predict_mask(self.offload.predictors[dense_idx], h2)
+        with get_tracer().span("sync"):
+            masks = np.asarray(masks)
         masks = masks & active[:, None]
         # feed the admission predictor: this layer's last true masks, plus an
         # EMA of per-column activation frequency over the active rows (the
@@ -1146,6 +1150,7 @@ class InferenceServer:
         n_slots = self.max_slots
         n_layers = runtime.n_layers
         req_io = np.zeros(n_slots)
+        tr = get_tracer()
         if self.prefetch and not runtime.prefetch_active:
             runtime.start_prefetch()        # one worker for the whole run
         la_params = self._la_params if self.prefetch else None
@@ -1157,12 +1162,13 @@ class InferenceServer:
             h2 = normed2[:, 0]
             masks = self._true_masks(dense_idx, h2, active)
             y, res = runtime.ffn_apply_batch(dense_idx, h2, masks)
-            flops = (2.0 * n_slots * res.merged.n_activated
-                     * runtime.n_mats * cfg.d_model)
-            self.scheduler.record_stage(dense_idx,
-                                        io_seconds=res.merged.io.seconds,
-                                        flops=flops)
-            np.add(req_io, res.req_io_seconds, out=req_io)
+            with tr.span("stage_accounting"):
+                flops = (2.0 * n_slots * res.merged.n_activated
+                         * runtime.n_mats * cfg.d_model)
+                self.scheduler.record_stage(dense_idx,
+                                            io_seconds=res.merged.io.seconds,
+                                            flops=flops)
+                np.add(req_io, res.req_io_seconds, out=req_io)
             return y[:, None]
 
         # Pipelined path: submit layer k+1's speculated prefetch, then
@@ -1173,22 +1179,26 @@ class InferenceServer:
             if dense_idx == 0 or la_params is None:
                 runtime.begin_layer(dense_idx, masks_true)   # depth 0
             if la_params is not None and dense_idx + 1 < n_layers:
-                spec = runtime.predict_lookahead(dense_idx, np.asarray(h2))
+                with tr.span("sync"):
+                    h_np = np.asarray(h2)
+                spec = runtime.predict_lookahead(dense_idx, h_np)
                 spec = spec & active[:, None]
                 runtime.begin_layer(dense_idx + 1, spec)
             y, res, meas = runtime.complete_layer(dense_idx, h2, masks_true)
-            flops = (2.0 * n_slots * res.merged.n_activated
-                     * runtime.n_mats * cfg.d_model)
-            self.scheduler.record_stage(dense_idx,
-                                        io_seconds=res.merged.io.seconds,
-                                        flops=flops, measured=meas)
-            np.add(req_io, res.req_io_seconds, out=req_io)
+            with tr.span("stage_accounting"):
+                flops = (2.0 * n_slots * res.merged.n_activated
+                         * runtime.n_mats * cfg.d_model)
+                self.scheduler.record_stage(dense_idx,
+                                            io_seconds=res.merged.io.seconds,
+                                            flops=flops, measured=meas)
+                np.add(req_io, res.req_io_seconds, out=req_io)
             return y[:, None]
 
         ffn_override = override_prefetch if self.prefetch else override
         t0 = time.perf_counter()
-        x = embed_tokens(self.params["embed"],
-                         jnp.asarray(self._cur)[:, None], cfg)
+        with tr.span("embed"):
+            x = embed_tokens(self.params["embed"],
+                             jnp.asarray(self._cur)[:, None], cfg)
         self.scheduler.begin_token()
         paged = self._pool is not None
         cache_groups = self._pool.cache_groups if paged else self._cache_groups
@@ -1201,9 +1211,11 @@ class InferenceServer:
             self._pool.cache_groups = cache_groups
         else:
             self._cache_groups = cache_groups
-        h = apply_norm(self.params["final_norm"], h, cfg)
-        logits = unembed(self.params["embed"], h, cfg)
-        rows = np.asarray(logits[:, 0], dtype=np.float32)   # ONE sync per token
+        with tr.span("unembed"):
+            h = apply_norm(self.params["final_norm"], h, cfg)
+            logits = unembed(self.params["embed"], h, cfg)
+        with tr.span("sync"):                               # ONE per token
+            rows = np.asarray(logits[:, 0], dtype=np.float32)
         token_wall = time.perf_counter() - t0
         timing = self.scheduler.end_token(
             compute_seconds=token_wall,

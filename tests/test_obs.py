@@ -9,6 +9,7 @@ when tracing is off; a traced server run emits exactly one `decode` span
 per emitted token; registered gauges read live object state; and
 `request_timeline(handle)` reconstructs a request's phase breakdown.
 """
+import collections
 import json
 import threading
 import time
@@ -264,14 +265,14 @@ def test_offload_trace_shows_prefetch_overlap(tracer, registry, rng):
     assert pf and ds
     assert len({p[2] for p in pf} & {d[2] for d in ds}) == 0  # separate lanes
     assert any(p[0] < d[1] and d[0] < p[1] for p in pf for d in ds)
-    # IOScheduler counter tracks rode along
-    assert any(e["ph"] == "C" and e["name"] == "io_model_ms" for e in evs)
+    # the IOScheduler's measured counter track rode along
+    assert any(e["ph"] == "C" and e["name"] == "io_measured_ms" for e in evs)
     # scheduler gauges registered by the server match its summary
     snap = registry.snapshot()
     summ = server.scheduler.summary()
     assert snap["gauges"]["scheduler.tokens"] == summ["tokens"]
-    assert snap["gauges"]["scheduler.overlap_efficiency"] == \
-        pytest.approx(summ["overlap_efficiency"])
+    assert snap["gauges"]["scheduler.measured_overlap_efficiency"] == \
+        pytest.approx(summ["measured_overlap_efficiency"])
 
 
 def test_request_timeline(tracer, registry, rng):
@@ -311,3 +312,100 @@ def test_disabled_server_run_emits_nothing(registry, rng):
     finally:
         server.close()
     assert get_tracer().n_events == 0
+
+
+# -- profiler clock ------------------------------------------------------------
+
+class _AnnotationRecorder:
+    """Stands in for `jax.profiler.TraceAnnotation`: records each name it
+    is opened with, and how many are still open."""
+    opened: list = []
+    depth = 0
+
+    def __init__(self, name, **kw):
+        assert not kw, "annotations carry no args"
+        self.name = name
+
+    def __enter__(self):
+        type(self).opened.append(self.name)
+        type(self).depth += 1
+        return self
+
+    def __exit__(self, *exc):
+        type(self).depth -= 1
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _AnnotationRecorder)
+    _AnnotationRecorder.opened = []
+    _AnnotationRecorder.depth = 0
+    return _AnnotationRecorder
+
+
+def _offload_run(seed=3):
+    """A tiny paged offload server with the prefetch worker, two requests
+    to the end; returns the runtime."""
+    cfg, model, params = _setup()
+    rt = build_offload_runtime(model, params, rng=np.random.default_rng(7),
+                               train_lookahead=True)
+    server = InferenceServer(model, params, max_slots=2, max_len=64,
+                             mode="offload", offload=rt, prefetch=True,
+                             page_size=8, num_pages=16)
+    rng = np.random.default_rng(seed)
+    try:
+        for i in range(2):
+            server.submit(Request(
+                uid=i, prompt=rng.integers(0, 128, 8 + i).astype(np.int32),
+                max_new_tokens=4))
+        server.drain()
+    finally:
+        server.close()
+    return rt
+
+
+def test_spans_open_balanced_repro_annotations(annotations, registry):
+    """Every span() (and only span()) of the recording tracer opens a
+    `repro.<name>` annotation, balanced, on whichever thread ran it."""
+    tr = enable_tracing()
+    try:
+        _offload_run()
+    finally:
+        disable_tracing()
+    assert annotations.depth == 0
+    spans = collections.Counter(
+        "repro." + e["name"] for e in tr.events()
+        if e["ph"] == "X" and e["tid"] < 1_000_000
+        and e["name"] != "decode")          # decode: complete() only
+    assert collections.Counter(annotations.opened) == spans
+    for name in ("decode_step", "attention", "ffn", "sync", "embed",
+                 "unembed", "stage_accounting", "prefill", "page_write",
+                 "prefetch", "step"):
+        assert "repro." + name in spans, name
+    # 2 dense layers: a mask read per layer, a lookahead read for every
+    # layer but the last, the logits read once per step
+    steps = spans["repro.decode_step"]
+    assert spans["repro.attention"] == spans["repro.ffn"] == 2 * steps
+    assert spans["repro.sync"] == (2 + 1 + 1) * steps
+
+
+def test_disabled_offload_run_records_and_annotates_nothing(annotations,
+                                                            registry):
+    assert get_tracer() is NULL_TRACER
+    _offload_run()
+    assert get_tracer().n_events == 0
+    assert annotations.opened == []
+
+
+def test_offload_counters_bound_each_other(registry):
+    """The true activated union is inside what the segment kernel serves,
+    which is inside the segment rows it reads."""
+    rt = _offload_run()
+    assert rt.ffn_kernel == "segments"
+    c = registry.snapshot()["counters"]
+    true, served, rows = (c["offload.true_union_neurons"],
+                          c["offload.served_neurons"],
+                          c["offload.segment_rows"])
+    assert 0 < true <= served <= rows
+    assert rows % rt.engine_cfg.kernel_seg_size == 0
