@@ -8,6 +8,7 @@ practice. Parameters and caches are stacked [G, ...] along the scan axis.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -17,7 +18,7 @@ from repro.configs.base import ModelConfig
 from repro.models import moe as moe_lib
 from repro.models import ssm
 from repro.kernels import ops
-from repro.obs import get_tracer
+from repro.obs import get_metrics, get_tracer
 from repro.models.kvcache import (KVCache, PagedKVCache, PagedQuantKVCache,
                                   QuantKVCache, SWACache, attend_full_cache,
                                   attend_swa_cache,
@@ -324,22 +325,88 @@ def _decode_positions(position: jnp.ndarray, B: int) -> jnp.ndarray:
     return jnp.broadcast_to(pos, (B, 1))
 
 
-def _mixer_decode(sp: Params, cj: Any, h: jnp.ndarray, pos_arr: jnp.ndarray,
-                  position: jnp.ndarray, cfg: ModelConfig, kind: str,
-                  window: int,
-                  page_tables: Optional[jnp.ndarray] = None
-                  ) -> Tuple[jnp.ndarray, Any]:
-    """One sublayer's mixer for a single decode token: (mix [B,1,d], new cache).
+def _is_paged(cj: Any) -> bool:
+    return isinstance(cj, (PagedKVCache, PagedQuantKVCache))
 
-    Shared by the jit'd scan path (stack_decode_step) and the host-driven
-    layerwise path (stack_decode_step_layerwise) so both run identical math.
-    `position` is a shared scalar or a per-slot [B] vector; the full-cache
-    writes pick the matching (slice vs per-row scatter) variant. Paged caches
-    scatter the write through `page_tables` [B, max_pages] and attend via
-    `kernels/ops.paged_decode_attention` — the XLA gather twin on CPU, the
-    Pallas paged-attention kernel elsewhere (per-slot positions required —
-    the paged layout exists for the continuous-batching server).
-    """
+
+def _paged_write(cj: Any):
+    """The arena's KV write, by its type (float or int8 with scales)."""
+    if isinstance(cj, PagedQuantKVCache):
+        return paged_quant_kv_write_rows
+    return paged_kv_write_rows
+
+
+def _attn_pre(sp: Params, cj: Any, h: jnp.ndarray, pos_arr: jnp.ndarray,
+              position: jnp.ndarray, page_tables: Optional[jnp.ndarray],
+              cfg: ModelConfig, write) -> Tuple[jnp.ndarray, Any]:
+    """Paged attention up to the kernel: norm1 -> QKV -> rope -> the KV
+    `write` (`_paged_write(cj)`) through `page_tables` [B, max_pages].
+    Returns (q [B, H, hd], new cache). Paged decode needs per-slot [B]
+    positions (the paged layout exists for the continuous-batching
+    server)."""
+    from repro.models.layers import _project_qkv
+    if page_tables is None:
+        raise ValueError("paged KV cache decode needs page_tables")
+    if jnp.asarray(position).ndim != 1:
+        raise ValueError("paged KV cache decode needs per-slot [B] "
+                         "positions (continuous batching)")
+    normed = apply_norm(sp["norm1"], h, cfg)
+    q, k, v = _project_qkv(sp["mixer"], normed, normed, cfg)
+    q = rope(q, pos_arr, cfg.rope_theta)
+    k = rope(k, pos_arr, cfg.rope_theta)
+    return q[:, 0], write(cj, k, v, position, page_tables)
+
+
+def _paged_attn(q: jnp.ndarray, cj: Any, page_tables: jnp.ndarray,
+                cur_pos: jnp.ndarray) -> jnp.ndarray:
+    """The paged-decode kernel over one layer's arena: [B, H, hd] fp32.
+    `kernels/ops.paged_decode_attention` dispatches the XLA gather twin on
+    CPU and the Pallas kernel elsewhere."""
+    if isinstance(cj, PagedQuantKVCache):
+        return ops.paged_decode_attention(q, cj.k, cj.v, page_tables, cur_pos,
+                                          k_scale=cj.k_scale,
+                                          v_scale=cj.v_scale)
+    return ops.paged_decode_attention(q, cj.k, cj.v, page_tables, cur_pos)
+
+
+def _attn_post(sp: Params, h: jnp.ndarray, out: jnp.ndarray,
+               cfg: ModelConfig, ffn: str
+               ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """Paged attention after the kernel: fold the kernel's [B, H, hd] fp32 to
+    the [B, 1, H*hd] residual layout at q's dtype -> wo -> residual ->
+    norm2. Returns (h, normed2; None without an FFN)."""
+    wo = sp["mixer"]["wo"]
+    B, H, hd = out.shape
+    mix = out.reshape(B, 1, H * hd).astype(jnp.result_type(h, wo))
+    h = h + mix @ wo
+    return h, _norm2(sp, h, cfg, ffn)
+
+
+def _norm2(sp: Params, h: jnp.ndarray, cfg: ModelConfig, ffn: str
+           ) -> Optional[jnp.ndarray]:
+    return None if ffn == "none" else apply_norm(sp["norm2"], h, cfg)
+
+
+def _sublayer_mix(sp: Params, cj: Any, h: jnp.ndarray, pos_arr: jnp.ndarray,
+                  position: jnp.ndarray, page_tables: Optional[jnp.ndarray],
+                  cfg: ModelConfig, kind: str, ffn: str, window: int
+                  ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], Any]:
+    """One sublayer's mixer for a single decode token, with its residual and
+    norm2: (h, normed2 [B,1,d] or None without an FFN, new cache).
+
+    The scan path (stack_decode_step) calls it inline; the layerwise path
+    (stack_decode_step_layerwise) runs it as one compiled call, or, for a
+    paged arena, as its three pieces `_attn_pre` -> `_paged_attn` ->
+    `_attn_post`, so both paths run identical math. `position` is a shared
+    scalar or a per-slot [B] vector; the full-cache writes pick the matching
+    (slice vs per-row scatter) variant."""
+    if _is_paged(cj):
+        q, cj = _attn_pre(sp, cj, h, pos_arr, position, page_tables, cfg,
+                          _paged_write(cj))
+        out = _paged_attn(q, cj, page_tables,
+                          jnp.asarray(position).astype(jnp.int32))
+        h, normed2 = _attn_post(sp, h, out, cfg, ffn)
+        return h, normed2, cj
     per_row = jnp.asarray(position).ndim == 1
     normed = apply_norm(sp["norm1"], h, cfg)
     if kind == "attn":
@@ -347,28 +414,7 @@ def _mixer_decode(sp: Params, cj: Any, h: jnp.ndarray, pos_arr: jnp.ndarray,
         q, k, v = _project_qkv(sp["mixer"], normed, normed, cfg)
         q = rope(q, pos_arr, cfg.rope_theta)
         k = rope(k, pos_arr, cfg.rope_theta)
-        if isinstance(cj, (PagedKVCache, PagedQuantKVCache)):
-            if page_tables is None:
-                raise ValueError("paged KV cache decode needs page_tables")
-            if not per_row:
-                raise ValueError("paged KV cache decode needs per-slot [B] "
-                                 "positions (continuous batching)")
-            cur_pos = jnp.asarray(position).astype(jnp.int32)
-            if isinstance(cj, PagedQuantKVCache):
-                cj = paged_quant_kv_write_rows(cj, k, v, position, page_tables)
-                out = ops.paged_decode_attention(
-                    q[:, 0], cj.k, cj.v, page_tables, cur_pos,
-                    k_scale=cj.k_scale, v_scale=cj.v_scale)
-            else:
-                cj = paged_kv_write_rows(cj, k, v, position, page_tables)
-                out = ops.paged_decode_attention(q[:, 0], cj.k, cj.v,
-                                                 page_tables, cur_pos)
-            # the kernel dispatcher (XLA gather twin on CPU, Pallas paged
-            # kernel elsewhere) returns [B, H, hd] fp32; fold back to the
-            # [B, 1, H*hd] residual layout at the model dtype
-            B, H, hd = out.shape
-            mix = out.reshape(B, 1, H * hd).astype(q.dtype)
-        elif isinstance(cj, SWACache):
+        if isinstance(cj, SWACache):
             cj = swa_write(cj, k, v, pos_arr)
             mix = attend_swa_cache(q, cj, pos_arr, window or cfg.sliding_window)
         elif isinstance(cj, QuantKVCache):
@@ -379,14 +425,39 @@ def _mixer_decode(sp: Params, cj: Any, h: jnp.ndarray, pos_arr: jnp.ndarray,
             cj = (kv_write_rows(cj, k, v, position) if per_row
                   else kv_write(cj, k, v, position))
             mix = attend_full_cache(q, cj, pos_arr)
-        return mix @ sp["mixer"]["wo"], cj
-    if kind == "mamba":
+        mix = mix @ sp["mixer"]["wo"]
+    elif kind == "mamba":
         y, cj = ssm.mamba_decode_step(sp["mixer"], normed[:, 0], cj, cfg)
+        mix = y[:, None]
     elif kind == "mlstm":
         y, cj = ssm.mlstm_decode_step(sp["mixer"], normed[:, 0], cj, cfg)
+        mix = y[:, None]
     else:
         y, cj = ssm.slstm_decode_step(sp["mixer"], normed[:, 0], cj, cfg)
-    return y[:, None], cj
+        mix = y[:, None]
+    h = h + mix
+    return h, _norm2(sp, h, cfg, ffn), cj
+
+
+def _compiled(fn, *static: str):
+    """`fn` as one jitted call with `static` arguments. Each trace counts in
+    the `model.mixer_traces` counter, so a retrace inside a serving window
+    (a changed shape, a Python scalar where an array belongs) shows."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        get_metrics().counter("model.mixer_traces").inc()
+        return fn(*args, **kwargs)
+    return jax.jit(traced, static_argnames=static)
+
+
+# The layerwise path's compiled mixer pieces, built once: what compiles
+# follows the sublayer's kind and its cache's type and shapes. The paged
+# kernel stays its own module between `_attn_pre` and `_attn_post`. The
+# arena's write goes in static, looked up by the caller on each call, so a
+# write replaced in this module compiles anew instead of hitting the cache.
+_attn_pre_jit = _compiled(_attn_pre, "cfg", "write")
+_attn_post_jit = _compiled(_attn_post, "cfg", "ffn")
+_sublayer_mix_jit = _compiled(_sublayer_mix, "cfg", "kind", "ffn", "window")
 
 
 def stack_decode_step(
@@ -411,11 +482,9 @@ def stack_decode_step(
             sp = group_params[f"sub_{j}"]
             cj = group_cache[f"sub_{j}"]
             kind, ffn = kinds[j], ffns[j]
-            mix, cj = _mixer_decode(sp, cj, h, pos_arr, position, cfg, kind,
-                                    window, page_tables=page_tables)
-            h = h + mix
+            h, normed2, cj = _sublayer_mix(sp, cj, h, pos_arr, position,
+                                           page_tables, cfg, kind, ffn, window)
             if ffn != "none":
-                normed2 = apply_norm(sp["norm2"], h, cfg)
                 if ffn == "dense":
                     if cfg.serve_sparse:
                         y2 = sparse_ffn_decode(sp["ffn"], sp["ffn_pred"], normed2, cfg)
@@ -468,13 +537,18 @@ def stack_decode_step_layerwise(
     `ffn_pre_act`, so calibration traces and serving agree on layer ids.
     `page_tables` routes attention sublayers through a paged arena exactly as
     in `stack_decode_step` — the one page table serves every layer group.
-    Each sublayer's mixer (with its residual) runs under a `repro.obs`
-    `attention` span and its FFN under an `ffn` span.
+
+    Each sublayer's mixer, residual and norm2 run as one compiled call
+    (`_sublayer_mix_jit`), or, on a paged arena, as three: `_attn_pre_jit`,
+    the paged-decode kernel (its own module, dispatched from here), and
+    `_attn_post_jit`. They run under a `repro.obs` `attention` span, and the
+    FFN under an `ffn` span.
     """
     P = stack_period(cfg)
     kinds, ffns = cfg.layer_kinds(), cfg.ffn_kinds()
     B = x.shape[0]
     pos_arr = _decode_positions(position, B)
+    cur_pos = jnp.asarray(position).astype(jnp.int32)
     tr = get_tracer()
     h = x
     dense_idx = 0
@@ -486,12 +560,18 @@ def stack_decode_step_layerwise(
             cj = group_cache[f"sub_{j}"]
             kind, ffn = kinds[j], ffns[j]
             with tr.span("attention"):
-                mix, cj = _mixer_decode(sp, cj, h, pos_arr, position, cfg,
-                                        kind, window, page_tables=page_tables)
-                h = h + mix
+                if _is_paged(cj):
+                    q, cj = _attn_pre_jit(sp, cj, h, pos_arr, position,
+                                          page_tables, cfg=cfg,
+                                          write=_paged_write(cj))
+                    out = _paged_attn(q, cj, page_tables, cur_pos)
+                    h, normed2 = _attn_post_jit(sp, h, out, cfg=cfg, ffn=ffn)
+                else:
+                    h, normed2, cj = _sublayer_mix_jit(
+                        sp, cj, h, pos_arr, position, page_tables, cfg=cfg,
+                        kind=kind, ffn=ffn, window=window)
             if ffn != "none":
                 with tr.span("ffn"):
-                    normed2 = apply_norm(sp["norm2"], h, cfg)
                     if ffn == "dense":
                         if ffn_override is not None:
                             y2 = ffn_override(dense_idx, normed2)

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.core.engine import EngineConfig
@@ -298,3 +299,112 @@ def test_io_summary_aggregates_from_sums(rng):
                    for e in runtime.engines)
     assert summ["effective_bandwidth"] == (useful / io_s if io_s else 0.0)
     assert summ["cache_hit_rate"] == hits / accesses
+
+
+# -- compiled layerwise mixer ------------------------------------------------------
+
+def _layerwise_tokens(cfg, params, cache_groups, page_tables, steps=6):
+    """Greedy decode through `stack_decode_step_layerwise` from per-slot
+    positions [0, 3]: (tokens [steps, B], logits [steps, B, V])."""
+    from repro.models import transformer
+    from repro.models.layers import apply_norm, embed_tokens, unembed
+    groups = transformer.unstack_groups(params["stack"], cfg)
+    tok = jnp.asarray([5, 9], jnp.int32)
+    pos = jnp.asarray([0, 3], jnp.int32)
+    toks, rows = [], []
+    for _ in range(steps):
+        x = embed_tokens(params["embed"], tok[:, None], cfg)
+        h, cache_groups = transformer.stack_decode_step_layerwise(
+            groups, x, pos, cache_groups, cfg, page_tables=page_tables)
+        logits = unembed(params["embed"],
+                         apply_norm(params["final_norm"], h, cfg), cfg)[:, 0]
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        toks.append(np.asarray(tok))
+        rows.append(np.asarray(logits, np.float32))
+        pos = pos + 1
+    return np.stack(toks), np.stack(rows)
+
+
+@pytest.mark.parametrize("cache", ["paged", "paged_int8", "contiguous", "swa"])
+def test_compiled_layerwise_step_matches_eager(cache):
+    """The layerwise step's compiled mixer (one jitted call a sublayer, or
+    pre -> paged kernel -> post on a paged arena) gives the eager step's
+    tokens, and its logits to float32 tolerance, on every cache kind."""
+    from repro.models import transformer
+    cfg = get_config("opt-350m", reduced=True, d_model=64, d_ff=256,
+                     n_layers=2, vocab_size=128, sliding_window=4,
+                     kv_quant=cache == "paged_int8")
+    params = build_model(cfg).init_params(jax.random.PRNGKey(11))
+    B, page_size, max_pages = 2, 4, 4
+    page_tables = None
+    if cache.startswith("paged"):
+        stacked = transformer.init_paged_stack_cache(cfg, B * max_pages,
+                                                     page_size)
+        page_tables = jnp.arange(B * max_pages, dtype=jnp.int32).reshape(
+            B, max_pages)
+    else:
+        stacked = transformer.init_stack_cache(cfg, B, page_size * max_pages,
+                                               swa=cache == "swa")
+    groups = transformer.unstack_groups(stacked, cfg)
+    toks, rows = _layerwise_tokens(cfg, params, groups, page_tables)
+    with jax.disable_jit():
+        ref_toks, ref_rows = _layerwise_tokens(cfg, params, groups,
+                                               page_tables)
+    np.testing.assert_array_equal(toks, ref_toks)
+    np.testing.assert_allclose(rows, ref_rows, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "contiguous"])
+def test_compiled_mixer_traces_once_and_kernel_runs_every_step(monkeypatch,
+                                                                paged):
+    """Through the offload server, `model.mixer_traces` grows only in the
+    first decode step (no retrace from a changed shape or a Python scalar),
+    and the paged-decode kernel is dispatched once per attention sublayer
+    per step at run time, not only while a trace runs: it stays its own
+    module, the one the kernel's roofline reads."""
+    from repro.kernels import ops
+    from repro.models import transformer
+    from repro.obs import get_metrics
+    from repro.serving.server import InferenceServer
+    jax.clear_caches()                  # the first step traces afresh
+    cfg = get_config("opt-350m", reduced=True, d_model=64, d_ff=256,
+                     n_layers=2, vocab_size=128)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(3))
+    rt = build_offload_runtime(model, params, rng=np.random.default_rng(3))
+    traces = get_metrics().counter("model.mixer_traces")
+    kernel_calls = []
+    real_kernel = ops.paged_decode_attention
+
+    def kernel(*a, **kw):
+        kernel_calls.append(1)
+        return real_kernel(*a, **kw)
+
+    steps = []                          # (traces added, kernel calls)
+    real_step = transformer.stack_decode_step_layerwise
+
+    def step(*a, **kw):
+        t0, k0 = traces.value, len(kernel_calls)
+        out = real_step(*a, **kw)
+        steps.append((traces.value - t0, len(kernel_calls) - k0))
+        return out
+
+    monkeypatch.setattr(ops, "paged_decode_attention", kernel)
+    monkeypatch.setattr(transformer, "stack_decode_step_layerwise", step)
+    kw = dict(page_size=4, num_pages=24) if paged else {}
+    server = InferenceServer(model, params, max_slots=2, max_len=48,
+                             mode="offload", offload=rt, **kw)
+    rng = np.random.default_rng(5)
+    try:
+        for uid, n in enumerate((5, 9)):
+            server.submit(Request(uid=uid, prompt=rng.integers(1, 127, n)
+                                  .tolist(), max_new_tokens=6))
+        results = server.drain()
+    finally:
+        server.close()
+    assert len(results) == 2
+    assert len(steps) >= 5
+    # pre and post on a paged arena, one fused call otherwise; P = 1 here
+    assert steps[0][0] == (2 if paged else 1)
+    assert [t for t, _ in steps[1:]] == [0] * (len(steps) - 1)
+    assert [k for _, k in steps] == [cfg.n_layers if paged else 0] * len(steps)
