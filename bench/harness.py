@@ -4,8 +4,12 @@ Everything that belongs to one configuration, traffic mix, per-layer metric
 or cell's limits is a file of its own, found by name:
 
   BENCHMARK.json                  cells, metrics, bounds
-  bench/configs/<config>.json     sizes, source, reduced keys, assumptions
-  bench/reference/<module>.py     the plain reference a config names
+  bench/configs/<config>.json     published sizes, source, reduced keys,
+                                  assumptions, and the program's `program`
+                                  configuration
+  bench/reference/<module>.py     the plain reference a config names:
+                                  `Dims.from_config`, `make_params`,
+                                  `forward_logits`
   bench/traffic/<traffic>.json    the mix the one generator reads
   bench/metrics/<metric>.py       `read(run)` -> value or None
   bench/limits/<cell>.json        the limits `correct` is decided by
@@ -140,16 +144,10 @@ def enable_cache() -> str:
 
 def program_config(cfg: Dict[str, Any]):
     """The program's ModelConfig for a bench config file: the program's own
-    entry for the architecture, with every size the file states."""
-    import dataclasses as dc
+    entry for `program.arch`, with every field the `program` block states."""
     from repro.configs import ALL_CONFIGS
-    base = ALL_CONFIGS[cfg["program_arch"]]
-    return dc.replace(
-        base, n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        d_ff=cfg["ffn_dim"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_attention_heads"], vocab_size=cfg["vocab_size"],
-        rope_theta=cfg["rope_theta"], activation=cfg["activation_function"],
-        param_dtype=cfg["torch_dtype"], compute_dtype=cfg["torch_dtype"])
+    fields = dict(cfg["program"])
+    return dataclasses.replace(ALL_CONFIGS[fields.pop("arch")], **fields)
 
 
 def check_layout(model, params) -> None:
@@ -472,7 +470,8 @@ def compare(cell: Cell, dims, params, sample: List[Served],
     acc: Dict[str, float] = collections.defaultdict(float)
     n_served, shares = 0, []
     for b, block in enumerate(members):
-        share = np.asarray(outs[STATED][b][1])
+        share = outs[STATED][b][1]          # None: the model states none
+        share = None if share is None else np.asarray(share)
         lgs = {k: np.asarray(o[b][0]) for k, o in outs.items()}
         cl = np.asarray(ctrl[b][0]) if control else None
         for r, i in enumerate(block):
@@ -492,27 +491,36 @@ def compare(cell: Cell, dims, params, sample: List[Served],
                                                  err_max)
                     acc[f"{w}_err_rms{k}"] += sq
             n_served += m
-            shares.append(share[:, r, :T0 - 1 + m].mean())
+            if share is not None:
+                shares.append(share[:, r, :T0 - 1 + m].mean())
     for key in acc:
         if "_err_rms" in key:
             acc[key] = float(np.sqrt(acc[key] / max(n_served * dims.vocab, 1)))
     acc["served_tokens"] = n_served
-    acc["token_share"] = float(np.mean(shares)) if shares else 0.0
+    if _stated_activations(cell) is not None:
+        acc["token_share"] = float(np.mean(shares)) if shares else 0.0
     return dict(acc)
+
+
+def _stated_activations(cell: Cell) -> Optional[Dict[str, Any]]:
+    """The activation share the configuration states, if it states one."""
+    return cell.config.get("assumed", {}).get("activations")
 
 
 def verdict(cell: Cell, got: Dict[str, float], failed: int,
             who: str = "") -> Dict[str, Dict[str, float]]:
     """Each number compared, beside its limit: the cell's logit checks read
-    for `who` ("" the program, "control_" the control in its place), the
-    configuration's activation share within its stated tolerance (of the
-    reference on the served sequences), and no failed request."""
-    act = cell.config["assumed"]["activations"]
+    for `who` ("" the program, "control_" the control in its place), where
+    the configuration states an activation share, the reference's share on
+    the served sequences within its stated tolerance, and no failed
+    request."""
     checks = {name: {"value": got[who + name], "limit": float(limit)}
               for name, limit in cell.limits["checks"].items()}
-    checks["activation_share_off"] = {
-        "value": abs(got["token_share"] / act["share"] - 1.0),
-        "limit": float(act["tolerance"])}
+    act = _stated_activations(cell)
+    if act is not None:
+        checks["activation_share_off"] = {
+            "value": abs(got["token_share"] / act["share"] - 1.0),
+            "limit": float(act["tolerance"])}
     checks["failed_requests"] = {"value": failed, "limit": 0}
     return checks
 
@@ -528,7 +536,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_start: float, control: bool = False) -> Dict[str, Any]:
     import jax
     from repro.models import build_model
-    from bench import roofline, spans as spans_mod, traffic, weights
+    from bench import roofline, spans as spans_mod, traffic
     from bench import trace as trace_mod
 
     counter = CompileCounter()
@@ -541,9 +549,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     cfg = program_config(cell.config)
     vocab = cfg.vocab_size
     t = time.monotonic()
-    params = jax.block_until_ready(
-        weights.make_params(ref, dims, cell.config["assumed"]["activations"],
-                            seed))
+    params = jax.block_until_ready(ref.make_params(dims, cell.config, seed))
     model = build_model(cfg)
     check_layout(model, params)
     log(f"weights: {time.monotonic() - t:.3f} s")
@@ -596,7 +602,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                     gaps.append(tj - tt[j - 1])
     run = Run(cell=cell, dims=dims, peak=peak, t0=t0, t1=t1, setup_s=setup_s,
               served=in_window, window_tokens=tokens, gaps=gaps,
-              n_mats=2 if cfg.activation != "silu" else 3,
+              n_mats=dims.n_mats,
               compiles_in_window=compiles, memory_peak_bytes=int(mem),
               work=tracer.log if tracer else None)
     if runtime is not None:
@@ -634,9 +640,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     got = compare(cell, dims, params, sample, tap.rows, control)
     log(f"reference over {len(sample)} requests, {got['served_tokens']} "
         f"served tokens: {time.monotonic() - t:.3f} s")
-    act = cell.config["assumed"]["activations"]
-    log(f"activation share per token {got['token_share']:.5f} (config "
-        f"{act['share']} +- {act['tolerance'] * 100:.0f}%)")
+    act = _stated_activations(cell)
+    if act is not None:
+        log(f"activation share per token {got['token_share']:.5f} (config "
+            f"{act['share']} +- {act['tolerance'] * 100:.0f}%)")
     log("readings: " + ", ".join(f"{k} {v!r}" for k, v in got.items()))
 
     checks = verdict(cell, got, failed)

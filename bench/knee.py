@@ -34,7 +34,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=15.0)
     args = ap.parse_args(argv)
 
-    from bench import harness, traffic, weights
+    from bench import harness, traffic
     cell = harness.load_cell(args.workload)
     if cell.mix["loop"] != "open":
         raise SystemExit("the knee is a property of an open-loop cell")
@@ -45,9 +45,7 @@ def main(argv=None) -> int:
         raise SystemExit("needs a TPU")
     ref = harness.reference_module(cell)
     dims = ref.Dims.from_config(cell.config)
-    params = weights.make_params(ref, dims,
-                                 cell.config["assumed"]["activations"],
-                                 args.seed)
+    params = ref.make_params(dims, cell.config, args.seed)
     model = build_model(harness.program_config(cell.config))
     server, runtime = harness._build_server(cell, model, params, args.seed)
     harness._warm_up(cell, server, runtime, args.seed, dims.vocab)

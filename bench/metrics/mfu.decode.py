@@ -11,7 +11,7 @@ def read(run):
     d = run.dims
     flops = sum(roofline.decode_token_flops(
         int(p), n_layers=d.n_layers, d_model=d.d_model, d_ff=d.d_ff,
-        n_heads=d.n_heads, n_kv_heads=d.n_heads, vocab=d.vocab,
+        n_heads=d.n_heads, n_kv_heads=d.n_kv_heads, vocab=d.vocab,
         n_mats=run.n_mats)
         for step in run.work.steps for p in step)
     return 100.0 * flops / run.window_s / run.peak.flops

@@ -12,6 +12,6 @@ def read(run):
         return None
     flops = sum(roofline.prefill_request_flops(
         len(s.prompt), n_layers=d.n_layers, d_model=d.d_model, d_ff=d.d_ff,
-        n_heads=d.n_heads, n_kv_heads=d.n_heads, vocab=d.vocab,
+        n_heads=d.n_heads, n_kv_heads=d.n_kv_heads, vocab=d.vocab,
         n_mats=run.n_mats) for s in done)
     return 100.0 * flops / run.window_s / run.peak.flops

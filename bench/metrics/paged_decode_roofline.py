@@ -14,7 +14,7 @@ def read(run):
     if seconds <= 0:
         return None
     d = run.dims
-    per_step = [roofline.paged_decode_work(pos, d.n_heads, d.n_heads,
+    per_step = [roofline.paged_decode_work(pos, d.n_heads, d.n_kv_heads,
                                            d.head_dim)
                 for pos in run.work.steps]
     work = roofline.total(per_step)

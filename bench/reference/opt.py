@@ -8,21 +8,27 @@ which the program makes and this reference follows: rotary positions instead
 of learned ones, no attention or FFN biases, untied embeddings.
 
 Written from that description in plain `jax.numpy`; nothing of the program is
-imported. `forward_logits` runs one layer at a time on blocks of sequences, so
-it fits beside nothing else on the chip.
+imported. The harness calls three things of a reference module, and nothing
+else: `Dims.from_config(cfg)` (the sizes, read from the configuration's
+published keys), `make_params(dims, cfg, seed)` (the served weights) and
+`forward_logits(params, blocks, dims, precision)`, which runs one layer at a
+time on blocks of sequences, so it fits beside nothing else on the chip.
 
 `init_params` lays the weights out as the program takes them: a dict with
 `embed`, `final_norm` and one stacked `stack/sub_0` group whose leaves carry
-the layer axis first.
+the layer axis first. `make_params` then shapes each FFN's activation
+statistics (`bench/weights.py`).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict
+from typing import Any, ClassVar, Dict
 
 import jax
 import jax.numpy as jnp
+
+from bench import weights
 
 LN_EPS = 1e-5
 
@@ -35,6 +41,11 @@ class Dims:
     n_heads: int
     vocab: int
     rope_theta: float
+    n_mats: ClassVar[int] = 2          # W_up and W_down per neuron
+
+    @property
+    def n_kv_heads(self) -> int:
+        return self.n_heads            # multi-head attention
 
     @property
     def head_dim(self) -> int:
@@ -69,6 +80,14 @@ def init_params(dims: Dims, key: jax.Array) -> Dict[str, Any]:
                     "w_down": nrm(ks[7], (L, f, d)) * f ** -0.5}}},
         "final_norm": ln(),
     }
+
+
+def make_params(dims: Dims, cfg: Dict[str, Any], seed: int) -> Dict[str, Any]:
+    """The served weights of `seed`, float32, on the default device: laid out
+    by `init_params`, each FFN shaped to the configuration's stated
+    activation statistics (`assumed.activations`)."""
+    return weights.make_params(init_params, attention, layer_norm, LN_EPS, dims,
+                               cfg["assumed"]["activations"], seed)
 
 
 def layer_norm(p, x):

@@ -1,7 +1,10 @@
 """Peaks table and the work functions the roofline and MFU readers use."""
+import types
+
 import pytest
 
-from bench import roofline
+from bench import harness, roofline
+from bench.tests import tinyroot
 
 
 def test_unknown_device_kind_raises():
@@ -35,6 +38,26 @@ def test_paged_decode_work_counts_filled_positions():
                                    head_dim=8)
     assert w.flops == 4 * 30 * 4 * 8
     assert w.bytes == 2 * 30 * 2 * 8 * 4 + 2 * 2 * 4 * 8 * 4
+
+
+def test_paged_decode_roofline_reader_counts_kv_heads():
+    """The reader counts the K/V bytes of `n_kv_heads` heads, not of every
+    query head: 8 query heads share 2 key/value heads of 16."""
+    reader = harness.load_module(tinyroot.REPO / "bench" / "metrics"
+                                 / "paged_decode_roofline.py")
+    dims = types.SimpleNamespace(n_layers=3, n_heads=8, n_kv_heads=2,
+                                 head_dim=16)
+    peak = roofline.Peak(1e12, 1e11, 1e9, "stated for the test")
+    run = types.SimpleNamespace(
+        dims=dims, peak=peak, work=types.SimpleNamespace(steps=[[9, 19]]),
+        trace=types.SimpleNamespace(seconds_matching=lambda names: 2e-6))
+    # 30 cached positions; K and V of 2 heads of 16 float32 each, plus q in
+    # and the output out for 2 slots of 8 heads, in each of 3 layers
+    nbytes = 3 * (2 * 30 * 2 * 16 * 4 + 2 * 2 * 8 * 16 * 4)
+    flops = 3 * 4 * 30 * 8 * 16
+    assert nbytes / peak.hbm_bytes_s > flops / peak.flops
+    assert reader.read(run) == pytest.approx(
+        100 * nbytes / peak.hbm_bytes_s / 2e-6, rel=1e-12)
 
 
 def test_model_flops():

@@ -1,6 +1,12 @@
 """Seeded weights, made on the device in one jitted call, with FFN activation
 statistics like a trained ReLU model's.
 
+For references of pre-LayerNorm decoders with a two-matrix ReLU FFN
+(`relu(LayerNorm(h) W_up) W_down`), laid out as one stacked `stack/sub_0`
+group: the OPT family. The reference passes in what of it this library runs
+(its initialiser, attention, LayerNorm and epsilon); a model of another shape
+makes its weights in its own reference module.
+
 Plain Gaussian weights make every ReLU pre-activation symmetric about zero:
 each neuron fires for about half the tokens, independently of every other, so
 the activated union of a batch is nearly all of `d_ff` and co-activation
@@ -74,7 +80,8 @@ def _thresholds(pre, share):
     return mu + sd * 0.5 * (lo + hi)
 
 
-def _shape_ffn(ref, params, key, dims, stats: Dict[str, Any]):
+def _shape_ffn(attention, layer_norm, eps, params, key, dims,
+               stats: Dict[str, Any]):
     """Replace each layer's `w_up` and pre-FFN LayerNorm bias (see module
     docstring), calibrating thresholds on a seeded token batch."""
     stack = params["stack"]["sub_0"]
@@ -91,11 +98,10 @@ def _shape_ffn(ref, params, key, dims, stats: Dict[str, Any]):
     def body(h, xs):
         p, lkey = xs
         e = _ffn_directions(lkey, d, f, stats["group_size"], stats["rho"])
-        h = h + ref.attention(p["mixer"], ref.layer_norm(p["norm1"], h),
-                              dims, "default")
+        h = h + attention(p["mixer"], layer_norm(p["norm1"], h), dims,
+                          "default")
         xf = h - h.mean(-1, keepdims=True)
-        n = xf * jax.lax.rsqrt(jnp.square(xf).mean(-1, keepdims=True)
-                               + ref.LN_EPS)
+        n = xf * jax.lax.rsqrt(jnp.square(xf).mean(-1, keepdims=True) + eps)
         pre = n.reshape(n_tok, d) @ e                          # [tokens, f]
         theta = _thresholds(pre, stats["share"])
         w_up = e - (theta / (c * d))[None, :]
@@ -114,20 +120,26 @@ def _shape_ffn(ref, params, key, dims, stats: Dict[str, Any]):
     return dict(params, stack={"sub_0": stack})
 
 
-@functools.partial(jax.jit, static_argnames=("ref", "dims", "stats_items"))
-def _make(key, ref, dims, stats_items):
+@functools.partial(jax.jit, static_argnames=(
+    "init_params", "attention", "layer_norm", "eps", "dims", "stats_items"))
+def _make(key, init_params, attention, layer_norm, eps, dims, stats_items):
     stats = dict(stats_items)
     k_init, k_ffn = jax.random.split(key)
-    return _shape_ffn(ref, ref.init_params(dims, k_init), k_ffn, dims, stats)
+    return _shape_ffn(attention, layer_norm, eps, init_params(dims, k_init),
+                      k_ffn, dims, stats)
 
 
 STATS_KEYS = ("share", "group_size", "rho", "input_bias", "embedding_std",
               "calibration_batch")
 
 
-def make_params(ref, dims, stats: Dict[str, Any], seed: int):
-    """The served weights of `seed`, float32, on the default device: the
-    reference module `ref` lays them out, then the FFNs are shaped."""
+def make_params(init_params, attention, layer_norm, eps: float, dims,
+                stats: Dict[str, Any], seed: int):
+    """The served weights of `seed`, float32, on the default device:
+    `init_params(dims, key)` lays them out, then each layer's FFN is shaped
+    on a calibration batch run through `attention(p, x, dims, precision)`
+    after `layer_norm(p, x)`; `eps` is the LayerNorm's epsilon."""
     items = tuple((k, tuple(stats[k]) if isinstance(stats[k], list)
                    else stats[k]) for k in STATS_KEYS)
-    return _make(seed_key(seed), ref, dims, items)
+    return _make(seed_key(seed), init_params, attention, layer_norm, float(eps),
+                 dims, items)
